@@ -34,5 +34,7 @@ def run() -> list[str]:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     for r in run():
         print(r)

@@ -204,6 +204,8 @@ if __name__ == "__main__":
                     choices=["fused", "bucketed"],
                     help="schedule for the pipeline-stage rows")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     for r in run(variants=tuple(args.variant or DEFAULT_VARIANTS),
                  pipeline=args.pipeline):
         print(r)

@@ -50,6 +50,8 @@ def main() -> None:
                     help="wall-clock samples per case for step_time "
                          "(default: the suite's baseline setting)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     want = args.suite or list(suites)
     unknown = [s for s in want if s not in suites]

@@ -237,6 +237,8 @@ def main() -> None:
                     metavar="DIR", help="write BENCH_serve.json to DIR "
                                         "(default: repo root)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     records = run_records(arch=args.arch, requests=args.requests,
                           num_slots=args.slots, seed=args.seed,

@@ -120,6 +120,8 @@ def main() -> None:
                     metavar="DIR", help="write BENCH_soak.json to DIR "
                                         "(default: repo root)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     records = run_records(arch=args.arch, steps=args.steps,
                           owners=args.owners, seed=args.seed)

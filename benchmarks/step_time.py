@@ -242,5 +242,7 @@ if __name__ == "__main__":
                     choices=["fused", "bucketed", "both"],
                     help="optimizer-step schedule for the owner-mode rows")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     for r in run(variant=args.variant, pipeline=args.pipeline):
         print(r)

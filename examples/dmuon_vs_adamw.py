@@ -18,6 +18,7 @@ from repro.core.muon import MuonConfig
 from repro.data.pipeline import DataConfig, batch_for_step
 from repro.models import model_fns
 from repro.train.step import init_state, make_loss_fn, make_train_step
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def train(cfg, mode, steps, lr):
@@ -45,6 +46,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=120)
     args = ap.parse_args()
+    enable_compile_cache()
     cfg = configs.get("smollm-360m", n_layers=4, d_model=256, n_heads=4,
                       n_kv_heads=2, d_ff=704, vocab=4096, head_dim=64,
                       remat=False)

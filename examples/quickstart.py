@@ -17,6 +17,7 @@ from repro.core.muon import MuonConfig
 from repro.data.pipeline import DataConfig, batch_for_step
 from repro.models import model_fns
 from repro.train.step import init_state, make_train_step
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -29,6 +30,7 @@ def main():
                     choices=["fused", "bucketed"],
                     help="optimizer-step schedule (docs/DESIGN.md §6)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = configs.get("smollm-360m", reduced=True)
     shapes = jax.eval_shape(lambda k: model_fns(cfg).init(cfg, k),

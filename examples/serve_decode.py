@@ -19,6 +19,7 @@ import jax
 from repro import configs
 from repro.models import model_fns
 from repro.serve import RequestQueue, Scheduler, ServeConfig, run_oneshot
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -45,6 +46,7 @@ def main():
                     help="paged: pool size in blocks (default: same bytes "
                          "as the contiguous reservation)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = configs.get(args.arch, reduced=True)
     m = model_fns(cfg)

@@ -22,6 +22,7 @@ from repro.data.pipeline import DataConfig, Pipeline, batch_for_step
 from repro.models import model_fns
 from repro.train.step import init_state, make_train_step
 from repro.train.train_state import TrainState
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def build(args):
@@ -53,6 +54,7 @@ def main():
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--full-360m", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg, plan, opt = build(args)
     n_params = cfg.param_count()

@@ -26,13 +26,6 @@ import numpy as np
 
 from repro.core.dedication import DedicationPlan
 
-# shard_map moved from jax.experimental to the jax namespace across
-# releases; resolve whichever this JAX provides once, here.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pragma: no cover — depends on the installed JAX
-    from jax.experimental.shard_map import shard_map
-
 
 def group_key_str(key) -> str:
     """Sanitize a group key for use as a state-dict key ('/' would collide
@@ -341,5 +334,9 @@ class OwnerLayout:
         in_specs = jax.tree.map(spec_of, tree_in)
         out_shape = jax.eval_shape(fn, tree_in)
         out_specs = jax.tree.map(spec_of, out_shape)
-        return shard_map(fn, mesh=self.mesh, in_specs=(in_specs,),
-                         out_specs=out_specs)(tree_in)
+        # check_vma=False: the Gram kernels' pallas_calls inside ``fn`` do not
+        # type-check under vma (no out_shape vma; the interpreter's slices
+        # reject mixed vma).  ``fn`` is local per stack row, so the check
+        # has nothing to catch here.
+        return jax.shard_map(fn, mesh=self.mesh, in_specs=(in_specs,),
+                             out_specs=out_specs, check_vma=False)(tree_in)

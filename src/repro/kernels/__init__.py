@@ -9,18 +9,14 @@ Modules:
   autotune   — block-shape search + persistent cache (paper Fig. 6)
 """
 
-from jax.experimental.pallas import tpu as _pltpu
-
-# The compiler-params container was renamed across JAX releases
-# (TPUCompilerParams -> CompilerParams).  Resolve whichever this JAX
-# provides once, here, so every kernel module stays version-agnostic.
-CompilerParams = getattr(_pltpu, "CompilerParams", None) \
-    or getattr(_pltpu, "TPUCompilerParams")
+import jax
 
 
-def tpu_compiler_params(**kwargs):
-    """Build pltpu compiler params under either API spelling."""
-    return CompilerParams(**kwargs)
+def interpret() -> bool:
+    """Whether the Pallas kernels run in interpret mode: on the CPU backend
+    only (tests and rehearsals), compiled everywhere else.  The one place the
+    choice is made; the public ops pass it down to the raw kernels."""
+    return jax.default_backend() == "cpu"
 
 
 from repro.kernels import autotune, ops, ref  # noqa: E402,F401

@@ -8,8 +8,8 @@ shapes under a VMEM budget with MXU-aligned (multiples of 128 where possible)
 dimensions — that is the space searched here.
 
 Two measurement backends:
-  * ``measured``   — wall-time the public op (interpret mode on this CPU-only
-    container; on a real TPU the same code path times the compiled kernel).
+  * ``measured``   — wall-time the public op (interpreted on the CPU backend;
+    on a TPU the same code path times the compiled kernel).
   * ``analytical`` — a TPU roofline scorer (VMEM-resident working set, MXU
     utilization of the block shape, grid overhead) used by the dry-run where
     nothing executes.  This mirrors how the measured-cost load balancer
@@ -73,22 +73,28 @@ def candidate_blocks(m: int, k: int, dtype_bytes: int = 4
                      ) -> Iterable[tuple[int, int]]:
     """Feasible (block_m, block_k) candidates under the VMEM budget.
 
+    Every edge is one the TPU lowering accepts: a multiple of the 128-wide
+    MXU no larger than the dim padded to 128, or the whole dim when it is
+    narrower than 128.  The multiples are powers of two, so symmul's common
+    padding ``lcm(bm, bk)`` is just the larger block.
+
     Working set per grid step: A (bm×bk) + B (bk×bm) + out/acc (bm×bm),
-    double-buffered inputs.  Blocks are MXU-aligned when the problem allows.
+    double-buffered inputs.
     """
+    def edges(dim: int) -> list[int]:
+        if dim <= _MXU:
+            return [dim]
+        padded = -(-dim // _MXU) * _MXU
+        return [s for s in (128, 256, 512, 1024) if s <= padded]
+
     budget = _VMEM_BYTES * _VMEM_FRACTION
-    sizes = [s for s in (64, 128, 256, 512, 1024) if s <= max(m, _MXU)]
-    if m < 64:
-        sizes = [m]
     out = []
-    for bm in sizes:
-        for bk in sizes:
-            if bm > m or bk > max(m, k):
-                continue
+    for bm in edges(m):
+        for bk in edges(k):
             ws = (2 * (bm * bk + bk * bm) + 2 * bm * bm) * dtype_bytes
             if ws <= budget:
                 out.append((bm, bk))
-    return out or [(min(m, 128), min(max(m, k), 128))]
+    return out or [(edges(m)[0], edges(k)[0])]
 
 
 def analytical_score(bm: int, bk: int, m: int, k: int,

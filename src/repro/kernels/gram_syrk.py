@@ -8,7 +8,8 @@ and the epilogue mirror (ops.py / ref.mirror_lower) reconstructs the dense
 output required by the subsequent Gram NS steps (paper §3.3).
 
 Structure mirrors ``symmul.py``: triangular grid via scalar-prefetched (i, j)
-tables, fp32 VMEM scratch accumulation, MXU-aligned autotuned block shapes.
+tables, fp32 VMEM scratch accumulation at HIGHEST precision, MXU-aligned
+autotuned block shapes.
 """
 
 from __future__ import annotations
@@ -21,7 +22,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.symmul import tri_index_tables
-from repro.kernels import tpu_compiler_params
 
 
 def _syrk_kernel(idx_i, idx_j, xi_ref, xj_ref, o_ref, acc_ref, *, nk: int):
@@ -34,6 +34,7 @@ def _syrk_kernel(idx_i, idx_j, xi_ref, xj_ref, o_ref, acc_ref, *, nk: int):
     acc_ref[...] += jax.lax.dot_general(
         xi_ref[0], xj_ref[0],
         dimension_numbers=(((1,), (1,)), ((), ())),  # X_i · X_jᵀ
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
 
     @pl.when(k == nk - 1)
@@ -94,7 +95,7 @@ def syrk_lower(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((batch, mp, mp), out_dtype),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         name="gram_syrk",
     )(jnp.asarray(ii), jnp.asarray(jj), x_p, x_p)

@@ -10,9 +10,8 @@ These are the ops the Gram NS iteration (core/gram_ns.py) dispatches to when
   * consults the autotuner cache for block shapes unless explicit
     ``block_m/block_k`` are given.
 
-On this CPU-only container the kernels execute in ``interpret=True`` mode for
-correctness validation; on TPU set ``interpret=False`` (the default flows from
-GramNSConfig.kernel_interpret).
+Interpret mode is chosen from the platform (``repro.kernels.interpret``):
+interpreted on the CPU backend, compiled on the TPU.
 """
 
 from __future__ import annotations
@@ -21,7 +20,7 @@ from typing import Optional
 
 import jax.numpy as jnp
 
-from repro.kernels import ref
+from repro.kernels import interpret, ref
 from repro.kernels.gram_syrk import syrk_lower
 from repro.kernels.symmul import symmul_lower
 
@@ -41,38 +40,37 @@ def _resolve_blocks(m: int, k: int, block_m: Optional[int],
 
 
 def syrk(x, *, block_m: Optional[int] = None, block_k: Optional[int] = None,
-         interpret: bool = True, out_dtype=None):
+         out_dtype=None):
     """G = X Xᵀ (dense symmetric output) for x of shape (..., m, n)."""
     xf, lead = _flatten_batch(x)
     bm, bk = _resolve_blocks(xf.shape[-2], xf.shape[-1], block_m, block_k,
                              "syrk", xf.dtype)
-    raw = syrk_lower(xf, block_m=bm, block_k=bk, interpret=interpret,
+    raw = syrk_lower(xf, block_m=bm, block_k=bk, interpret=interpret(),
                      out_dtype=out_dtype)
     return ref.mirror_lower(raw).reshape(lead + raw.shape[-2:])
 
 
 def symmul(a, b, *, block_m: Optional[int] = None,
-           block_k: Optional[int] = None, interpret: bool = True,
-           out_dtype=None):
+           block_k: Optional[int] = None, out_dtype=None):
     """C = A B for symmetric commuting A, B of shape (..., m, m)."""
     af, lead = _flatten_batch(a)
     bf, _ = _flatten_batch(b)
     bm, bk = _resolve_blocks(af.shape[-1], af.shape[-1], block_m, block_k,
                              "symmul", af.dtype)
     raw = symmul_lower(af, bf, epilogue="plain", block_m=bm, block_k=bk,
-                       interpret=interpret, out_dtype=out_dtype)
+                       interpret=interpret(), out_dtype=out_dtype)
     return ref.mirror_lower(raw).reshape(lead + raw.shape[-2:])
 
 
 def gram_poly(g, a: float, b: float, c: float, *,
               block_m: Optional[int] = None, block_k: Optional[int] = None,
-              interpret: bool = True, out_dtype=None):
+              out_dtype=None):
     """P = aI + bG + cG² with the polynomial fused into the G@G epilogue."""
     gf, lead = _flatten_batch(g)
     bm, bk = _resolve_blocks(gf.shape[-1], gf.shape[-1], block_m, block_k,
                              "gram_poly", gf.dtype)
     raw = symmul_lower(gf, gf, epilogue="gram_poly",
                        coeffs=(float(a), float(b), float(c)),
-                       block_m=bm, block_k=bk, interpret=interpret,
+                       block_m=bm, block_k=bk, interpret=interpret(),
                        out_dtype=out_dtype)
     return ref.mirror_lower(raw).reshape(lead + raw.shape[-2:])

@@ -7,6 +7,13 @@ dry-run lowers this path and the roofline harness applies the analytic
 symmetric-kernel FLOP adjustment — see docs/DESIGN.md §2).
 
 All functions accept arbitrary leading batch dims and accumulate in fp32.
+Every product runs at HIGHEST precision, i.e. exact fp32 on the TPU's MXU.
+At the TPU's default precision an fp32 matmul rounds its operands to bf16,
+and the Gram recurrence does not survive that: rounding pushes the small
+eigenvalues of a near-low-rank momentum (early training) below zero, where
+the NS polynomial grows them without bound — updates of order 1e12 on a
+four-chip smollm-360m run.  Standard NS on X is stable under the same
+rounding.
 """
 
 from __future__ import annotations
@@ -15,30 +22,32 @@ import jax
 import jax.numpy as jnp
 
 
-def _bmm(a: jax.Array, b: jax.Array) -> jax.Array:
+def bmm(a: jax.Array, b: jax.Array) -> jax.Array:
+    """Batched (…, m, k) @ (…, k, n), fp32 accumulation, HIGHEST precision."""
     out = jax.lax.dot_general(
         a, b,
         dimension_numbers=(((a.ndim - 1,), (b.ndim - 2,)),
                            (tuple(range(a.ndim - 2)), tuple(range(b.ndim - 2)))),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
     return out.astype(a.dtype)
 
 
 def syrk_ref(x: jax.Array) -> jax.Array:
     """G = X Xᵀ for X of shape (..., m, n); output (..., m, m), symmetric."""
-    return _bmm(x, x.mT)
+    return bmm(x, x.mT)
 
 
 def symmul_ref(a: jax.Array, b: jax.Array) -> jax.Array:
     """C = A B for symmetric commuting A, B (C symmetric). Shapes (..., m, m)."""
-    return _bmm(a, b)
+    return bmm(a, b)
 
 
 def gram_poly_ref(g: jax.Array, a: float, b: float, c: float) -> jax.Array:
     """P = aI + bG + c(G@G) for symmetric G of shape (..., m, m)."""
     m = g.shape[-1]
     eye = jnp.eye(m, dtype=g.dtype)
-    return (a * eye + b * g + c * _bmm(g, g)).astype(g.dtype)
+    return (a * eye + b * g + c * bmm(g, g)).astype(g.dtype)
 
 
 def mirror_lower(c_raw: jax.Array) -> jax.Array:

@@ -22,9 +22,12 @@ Layout notes (TPU):
   * the (i, j) block coordinates of the triangular grid are delivered via
     scalar prefetch (host-precomputed int32 tables) so the index maps stay
     scalar-core friendly;
-  * accumulation is fp32 in VMEM scratch regardless of the operand dtype.
+  * accumulation is fp32 in VMEM scratch regardless of the operand dtype,
+    and every product asks for HIGHEST precision, as ``ref.py`` does (the
+    Gram recurrence diverges when its products round to bf16).
 
-Validated on CPU via ``interpret=True`` against ``ref.py`` (tests/test_kernels.py).
+Validated in interpret mode on the CPU backend against ``ref.py``
+(tests/test_kernels.py); compiled for a v5e in tests/test_tpu_compile.py.
 """
 
 from __future__ import annotations
@@ -39,7 +42,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def tri_index_tables(n_blocks: int) -> tuple[np.ndarray, np.ndarray]:
@@ -59,7 +62,7 @@ def _plain_kernel(idx_i, idx_j, a_ref, b_ref, o_ref, acc_ref, *, nk: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(a_ref[0], b_ref[0],
+    acc_ref[...] += jnp.dot(a_ref[0], b_ref[0], precision=_HIGHEST,
                             preferred_element_type=jnp.float32)
 
     @pl.when(k == nk - 1)
@@ -76,7 +79,7 @@ def _gram_poly_kernel(idx_i, idx_j, a_ref, b_ref, g_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(a_ref[0], b_ref[0],
+    acc_ref[...] += jnp.dot(a_ref[0], b_ref[0], precision=_HIGHEST,
                             preferred_element_type=jnp.float32)
 
     a_c, b_c, c_c = coeffs
@@ -130,8 +133,12 @@ def symmul_lower(
 
     batch, m, _ = a.shape
     out_dtype = out_dtype or a.dtype
-    bm = min(block_m, m)
-    bk = min(block_k, m)
+    # A block no wider than the operand padded to the 128-lane tiling: the
+    # whole dim below 128, else a multiple of 128 the TPU lowering accepts
+    # (clamping to an unaligned m would pad to lcm(m, bk) instead).
+    cap = m if m < 128 else -(-m // 128) * 128
+    bm = min(block_m, cap)
+    bk = min(block_k, cap)
     # Pad both axes to a common multiple of the row- and k-block sizes so the
     # (i, j) block tables index every operand consistently.
     step = math.lcm(bm, bk)
@@ -169,7 +176,7 @@ def symmul_lower(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((batch, mp, mp), out_dtype),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         name=f"symmul_{epilogue}",
     )(jnp.asarray(ii), jnp.asarray(jj), *operands)

@@ -6,14 +6,14 @@ touches jax device state — the dry-run sets XLA_FLAGS before first init.
 
 from __future__ import annotations
 
-import jax
+from repro.runtime.elastic import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips per pod; 2×16×16 = 512 chips across two pods."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 # TPU v5e hardware constants (roofline denominators)

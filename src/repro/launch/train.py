@@ -1,10 +1,11 @@
 """Production training driver on the resilient supervisor loop.
 
     PYTHONPATH=src python -m repro.launch.train --arch smollm-360m \
-        --reduced --steps 50 --opt owner --ckpt-dir /tmp/ckpt
+        --reduced --seq 128 --steps 50 --opt owner --ckpt-dir /tmp/ckpt
 
-On real hardware this launches against the production mesh; on this CPU
-container use --reduced for the smoke-scale config.  The run is supervised
+On one chip it trains the full-width config; on the CPU backend use
+--reduced (and a short --seq) for the smoke-scale config.  --mesh lays a
+(data, model) mesh over every visible device.  The run is supervised
 by ``runtime/resilient.py``: streaming deterministic pipeline with a
 checkpointable cursor, rotating async checkpoints (train tree + data state),
 straggler monitoring with online re-dedication, and elastic recovery from
@@ -24,6 +25,7 @@ from repro import configs
 from repro.core.gram_ns import GramNSConfig
 from repro.core.muon import MuonConfig
 from repro.data.pipeline import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime.elastic import remesh
 from repro.runtime.faults import FaultPlan
 from repro.runtime.resilient import ResilientConfig, ResilientLoop
@@ -41,8 +43,10 @@ def main():
     ap.add_argument("--strategy", default="load_balance",
                     choices=["load_balance", "greedy", "lpt", "round_robin",
                              "rank0", "xor"])
-    ap.add_argument("--batch", type=int, default=8)
-    ap.add_argument("--seq", type=int, default=128)
+    # smollm-360m at full width, 2048 x 4 tokens per step, fits one 16 GiB
+    # TPU v5e in owner mode and in adamw mode (the step donates its state)
+    ap.add_argument("--batch", type=int, default=4)
+    ap.add_argument("--seq", type=int, default=2048)
     ap.add_argument("--lr", type=float, default=0.02)
     ap.add_argument("--accum", type=int, default=1)
     ap.add_argument("--pipeline", default="fused",
@@ -63,6 +67,7 @@ def main():
     ap.add_argument("--rebalance-window", type=int, default=20)
     ap.add_argument("--rebalance-threshold", type=float, default=1.3)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = configs.get(args.arch, reduced=args.reduced)
     if cfg.frontend is not None or cfg.encdec:

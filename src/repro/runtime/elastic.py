@@ -46,14 +46,26 @@ def viable_mesh_shape(n_devices: int, prefer_model: int = 16):
     return (n_devices // model, model)
 
 
+def make_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices: Optional[Sequence] = None):
+    """The one mesh constructor: ``devices`` (default: all) laid out row-major
+    over ``shape``, every axis Auto.  The optimizer and model place arrays
+    with ``with_sharding_constraint``/``NamedSharding`` specs, which JAX
+    accepts only on Auto axes (``jax.make_mesh`` defaults to Explicit)."""
+    import jax
+    from jax.sharding import AxisType, Mesh
+    devices = list(devices if devices is not None else jax.devices())
+    n = int(np.prod(shape))
+    arr = np.asarray(devices[:n]).reshape(tuple(shape))
+    return Mesh(arr, tuple(axes), axis_types=(AxisType.Auto,) * len(axes))
+
+
 def remesh(devices: Optional[Sequence] = None, prefer_model: int = 16):
     """Build a mesh over the currently-live devices."""
     import jax
     devices = list(devices if devices is not None else jax.devices())
     shape = viable_mesh_shape(len(devices), prefer_model)
-    arr = np.asarray(devices[:shape[0] * shape[1]]).reshape(shape)
-    from jax.sharding import Mesh
-    return Mesh(arr, ("data", "model"))
+    return make_mesh(shape, ("data", "model"), devices)
 
 
 @dataclass

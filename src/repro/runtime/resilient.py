@@ -62,7 +62,6 @@ class ResilientConfig:
     ckpt_every: int = 0             # 0 = no checkpointing
     strategy: str = "greedy"        # dedication strategy for every (re)plan
     accum_steps: int = 1
-    donate: bool = False            # buffer donation in the jit'd step
     # straggler policy
     rebalance: bool = True
     window: int = 8                 # monitor window (steps)
@@ -169,9 +168,11 @@ class ResilientLoop:
         self.opt = api.Muon(plan, self.mesh, config=self.muon_cfg)
         sig = self._plan_signature(plan)
         if sig not in self._step_cache:
+            # The step donates the old state: the loop keeps no reference
+            # to it, and checkpoints copy to host before the next step.
             self._step_cache[sig] = make_train_step(
                 self.model_cfg, self.opt, self.mesh,
-                accum_steps=self.rcfg.accum_steps, donate=self.rcfg.donate)
+                accum_steps=self.rcfg.accum_steps, donate=True)
         self.step_fn = self._step_cache[sig]
 
     # --------------------------------------------------------- checkpoints
@@ -227,17 +228,26 @@ class ResilientLoop:
                                 opt_state, self.state.loss_ema)
 
     def _rebalance(self, speed: np.ndarray, step: int) -> None:
-        """Re-solve the dedication with measured speeds; migrate in place."""
+        """Re-solve the dedication with measured speeds; migrate in place.
+
+        The re-solved plan is adopted only if it lowers the makespan under
+        the measured speeds.  A window that straddles the onset of a
+        slowdown (or wall-clock noise on a loaded host) can trip the monitor
+        at a mild speed gap, where the greedy re-solve is no better than the
+        live plan; migrating to it would cost a reshard and gain nothing,
+        and the monitor re-fires on the next step with a fuller window."""
         t0 = time.perf_counter()
         old_plan = self.plan
         new_plan = self._plan_for(num_owners=self.num_owners, speed=speed)
-        self._migrate(new_plan)
-        latency = time.perf_counter() - t0
         cm = new_plan.cost_model or old_plan.cost_model
         before = after = None
         if cm is not None:
             before = old_plan.assignment.makespan(cm, speed=speed)
             after = new_plan.assignment.makespan(cm, speed=speed)
+            if after >= before:
+                return
+        self._migrate(new_plan)
+        latency = time.perf_counter() - t0
         self._plan_speed = np.asarray(speed, float)
         self._last_plan_change = step
         self.monitor.reset()

@@ -161,9 +161,7 @@ class PreemptedSlot:
     recurrent: Optional[Any] = None   # {leaf: (L, ...)} per-slot state rows
 
 
-@functools.partial(
-    jax.jit, static_argnums=(4,),
-    donate_argnums=(0,) if jax.default_backend() != "cpu" else ())
+@functools.partial(jax.jit, static_argnums=(4,), donate_argnums=(0,))
 def _scatter_blocks(pool_leaves, row_leaves, ids, row, bs: int):
     """Copy the first len(ids) blocks of batch row ``row`` of a contiguous
     prefilled cache into physical pool blocks ``ids`` (insert path).

@@ -63,11 +63,6 @@ class ServeConfig:
                                           # steps (tests; paged mode only)
 
 
-def _donate(*idx):
-    """Buffer donation helps on accelerators; CPU warns and ignores it."""
-    return idx if jax.default_backend() != "cpu" else ()
-
-
 class Scheduler:
     """One model, one fixed decode batch, many requests."""
 
@@ -136,15 +131,15 @@ class Scheduler:
             self._chunk = jax.jit(
                 lambda p, t, c, pos: serve_fns.prefill_chunk_fn(
                     cfg, p, t, c, pos),
-                donate_argnums=_donate(2))
+                donate_argnums=(2,))
         self._decode = jax.jit(
             lambda p, t, c, pos: serve_fns.decode_fn(cfg, p, t, c, pos),
-            donate_argnums=_donate(2))
+            donate_argnums=(2,))
         if self._use_tables:
             self._decode_paged = jax.jit(
                 lambda p, t, c, pos, bt: serve_fns.decode_fn(
                     cfg, p, t, c, pos, block_tables=bt),
-                donate_argnums=_donate(2))
+                donate_argnums=(2,))
             # hybrid recurrent leaves sit at the slot batch, so a batch-1
             # chunked prefill cannot stream into the live cache — hybrids
             # stage chunked prompts contiguously and scatter on insert
@@ -153,7 +148,7 @@ class Scheduler:
                 self._chunk_paged = jax.jit(
                     lambda p, t, c, pos, bt: serve_fns.prefill_chunk_fn(
                         cfg, p, t, c, pos, block_tables=bt),
-                    donate_argnums=_donate(2))
+                    donate_argnums=(2,))
         else:
             self._direct_chunk = False
 
@@ -384,7 +379,7 @@ def _oneshot_fns(cfg, max_len: int, dt):
         prefill = jax.jit(lambda p, t: serve_fns.prefill_fn(
             cfg, p, t, max_len, cache_dtype=dt))
     decode = jax.jit(lambda p, t, c, pos: serve_fns.decode_fn(
-        cfg, p, t, c, pos), donate_argnums=_donate(2))
+        cfg, p, t, c, pos), donate_argnums=(2,))
     return prefill, decode
 
 

@@ -44,9 +44,7 @@ class Slot:
             self.tokens = []
 
 
-@functools.partial(
-    jax.jit,
-    donate_argnums=(0,) if jax.default_backend() != "cpu" else ())
+@functools.partial(jax.jit, donate_argnums=(0,))
 def _write_row(dcache, rcache, slot, row):
     """Copy batch row ``row`` of a prefilled cache into batch row ``slot``
     of the decode cache, for every leaf (axis 1 is batch everywhere)."""

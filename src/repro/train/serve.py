@@ -13,16 +13,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.models import model_fns, sharding as shard_rules
-
-# PartitionSpec's import home has moved across JAX releases; resolve the
-# canonical class once, here (same shim pattern as core/owner_comms.py's
-# shard_map and kernels/__init__.py's CompilerParams).
-PartitionSpec = getattr(jax.sharding, "PartitionSpec", None)
-if PartitionSpec is None:  # pragma: no cover — depends on the installed JAX
-    from jax.interpreters.pxla import PartitionSpec
 
 
 def prefill_fn(cfg, params, tokens, max_len: int, *,

@@ -27,6 +27,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import api
 from repro.core.gram_ns import GramNSConfig
 from repro.core.muon import MuonConfig
+from repro.runtime.elastic import make_mesh
 
 
 def tree(seed=0):
@@ -45,7 +46,7 @@ def tree(seed=0):
 
 def main():
     assert jax.device_count() == 8, jax.device_count()
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
 
     params = tree()
     grads = jax.tree.map(
@@ -188,6 +189,23 @@ def main():
             np.asarray(jax.device_get(flat_ref[path]), np.float32),
             rtol=1e-3, atol=1e-5, err_msg=path)
     print("pre-staged accumulation under mesh: OK")
+
+    # (8) the Pallas kernels inside the owner-local shard_map: jax.shard_map
+    # checks varying manual axes (vma) by default, and must accept the
+    # pallas_calls Gram NS issues there.  Kernel blocks sum in another order
+    # than the jnp dots: same tolerance as test_kernels' end-to-end check.
+    opt_k = api.Muon(plan, mesh=mesh, config=dataclasses.replace(
+        cfg, ns=GramNSConfig(num_steps=5, use_kernels=True)))
+    uk, _ = jax.jit(opt_k.update)(grads_sh, state, params_sh)
+    for (kp, a), (_, b) in zip(
+            jax.tree_util.tree_leaves_with_path(updates_sh),
+            jax.tree_util.tree_leaves_with_path(uk)):
+        np.testing.assert_allclose(
+            np.asarray(jax.device_get(a), np.float32),
+            np.asarray(jax.device_get(b), np.float32),
+            rtol=1e-4, atol=1e-4,
+            err_msg="/".join(str(getattr(k, 'key', k)) for k in kp))
+    print("Pallas kernels inside shard_map under mesh: OK")
     print("ALL DISTRIBUTED CHECKS PASSED")
 
 

@@ -71,6 +71,20 @@ def test_bf16_input_supported():
                                np.asarray(ref), atol=5e-2)
 
 
+def test_every_gram_product_at_highest_precision():
+    """The Gram recurrence diverges when its products round to bf16 (the
+    TPU's default for fp32 matmuls) on near-low-rank momentum, so every
+    product it issues must ask for HIGHEST whatever the ambient default."""
+    m = _rand((4, 32, 96), seed=2)
+    with jax.default_matmul_precision("bfloat16"):
+        jaxpr = jax.make_jaxpr(lambda x: gram_newton_schulz(
+            x, GramNSConfig(num_steps=5), assume_short_fat=True))(m)
+    dots = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "dot_general"]
+    assert len(dots) == 4 * 5 - 3 + 2      # symmetric products + G₀ + Q·X₀
+    for e in dots:
+        assert e.params["precision"] == (jax.lax.Precision.HIGHEST,) * 2
+
+
 def test_coefficient_schedules():
     sched = get_coefficients("polar_express", 10)
     assert len(sched) == 10

