@@ -6,7 +6,7 @@
 
 Prints ``name,us_per_call,derived`` CSV.  Wall-clock rows are measured on
 this host (XLA:CPU, 1 device); mesh-scale rows are derived from the measured
-cost model / dry-run artifacts and say so in ``derived``.
+cost model and say so in ``derived``.
 
 Suites that expose ``run_records()`` additionally emit versioned
 ``BENCH_<suite>.json`` files under ``--json DIR`` (schema in
@@ -25,15 +25,13 @@ import traceback
 
 def main() -> None:
     from benchmarks import (batching, breakdown, load_balance_bench,
-                            roofline_table, serve_bench, soak_bench,
-                            step_time)
+                            serve_bench, soak_bench, step_time)
     from benchmarks.common import record_to_csv, write_bench_json
     suites = {
         "step_time": step_time,              # Table 1 / Fig 8
         "breakdown": breakdown,              # Table 2
         "batching": batching,                # Fig 7
         "load_balance": load_balance_bench,  # §3.4
-        "roofline": roofline_table,          # §Roofline (from dry-run)
         "serve": serve_bench,                # continuous-batching tier
         "soak": soak_bench,                  # fault-injected resilience drill
     }

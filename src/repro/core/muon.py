@@ -252,7 +252,12 @@ def _owner_update(plan: DedicationPlan, gm, pm, state: MuonState,
     ``cfg.pipeline`` selects the schedule: 'fused' is the one-phase
     post-backward program below; 'bucketed' delegates to the per-Gram-bucket
     stage_in/compute/publish pipeline (core/pipeline.py) — same math, ordered
-    so the staged comms overlap the compute wavefront."""
+    so the staged comms overlap the compute wavefront.
+
+    The three sections run under the named scopes ``dmuon.stage_in``,
+    ``dmuon.orthogonalize`` and ``dmuon.publish``.  In this fused path the
+    momentum update lies inside ``dmuon.stage_in``, with the compression,
+    pack and owner constraint (the all-to-all to owners on a mesh)."""
     if cfg.pipeline == "bucketed":
         from repro.core.pipeline import BucketPipeline
         pipe = BucketPipeline(plan, cfg, mesh, spec)
@@ -262,41 +267,43 @@ def _owner_update(plan: DedicationPlan, gm, pm, state: MuonState,
     new_momentum: Dict[str, jax.Array] = {}
 
     # --- gradient routing: training layout -> owner layout (reduce-to-owner)
-    grads_for_pack, new_ef = compress_with_error_feedback(
-        gm, state.error_feedback, cfg)
-
     pdt = jnp.dtype(cfg.pack_dtype)
     packed_mom: Dict[str, jax.Array] = {}
     skey_to_key = {group_key_str(key): key for key in plan.groups}
-    for key, g in plan.groups.items():
-        g_packed = layout.pack(key, {p: grads_for_pack[p].astype(pdt)
-                                     for p in g.leaf_paths})
-        skey = group_key_str(key)
-        mom = state.momentum[skey].astype(pdt)
-        mom, eff = momentum_update(mom, g_packed, cfg)
-        new_momentum[skey] = layout.constrain(
-            mom.astype(jnp.dtype(cfg.momentum_dtype)))
-        packed_mom[skey] = layout.constrain(eff)
+    with jax.named_scope("dmuon.stage_in"):
+        grads_for_pack, new_ef = compress_with_error_feedback(
+            gm, state.error_feedback, cfg)
+        for key, g in plan.groups.items():
+            g_packed = layout.pack(key, {p: grads_for_pack[p].astype(pdt)
+                                         for p in g.leaf_paths})
+            skey = group_key_str(key)
+            mom = state.momentum[skey].astype(pdt)
+            mom, eff = momentum_update(mom, g_packed, cfg)
+            new_momentum[skey] = layout.constrain(
+                mom.astype(jnp.dtype(cfg.momentum_dtype)))
+            packed_mom[skey] = layout.constrain(eff)
 
     # --- owner-side orthogonalization via the variant's pluggable backend
     # (batched Gram NS by default; bucket-fused / NorMuon / MuonBP by name).
     ortho_fn = make_orthogonalizer(spec.orthogonalizer, cfg)
-    ortho, new_vstate = ortho_fn(packed_mom, step=state.step,
-                                 state=state.variant_state, layout=layout,
-                                 cfg=cfg)
+    with jax.named_scope("dmuon.orthogonalize"):
+        ortho, new_vstate = ortho_fn(packed_mom, step=state.step,
+                                     state=state.variant_state,
+                                     layout=layout, cfg=cfg)
 
     # --- publication: owner layout -> training layout + scale/wd/lr.
     # The resharded tensor stays in pack_dtype; fp32 casting before the
     # all-to-all would double the publish volume (and at 1T scale the fp32
     # temp alone exceeds HBM).
     matrix_updates: Dict[str, jax.Array] = {}
-    for skey, o in ortho.items():
-        key = skey_to_key[skey]
-        m, n = plan.groups[key].key
-        s = scale_factor(m, n, cfg.scale_mode)
-        per_leaf = layout.unpack(key, o.astype(pdt) * s)
-        for p, upd in per_leaf.items():
-            matrix_updates[p] = apply_wd_and_lr(upd, pm[p], cfg)
+    with jax.named_scope("dmuon.publish"):
+        for skey, o in ortho.items():
+            key = skey_to_key[skey]
+            m, n = plan.groups[key].key
+            s = scale_factor(m, n, cfg.scale_mode)
+            per_leaf = layout.unpack(key, o.astype(pdt) * s)
+            for p, upd in per_leaf.items():
+                matrix_updates[p] = apply_wd_and_lr(upd, pm[p], cfg)
     return matrix_updates, new_momentum, new_ef, new_vstate
 
 

@@ -171,14 +171,19 @@ class BucketPipeline:
                  dtype=None) -> Dict[str, jax.Array]:
         """Pack one bucket's gradients and issue the staged all-to-all to the
         owner layout.  ``dtype`` casts the leaves before packing (pack_dtype
-        on the direct path; the accumulator dtype when pre-staging)."""
+        on the direct path; the accumulator dtype when pre-staging).
+
+        Runs under the named scope ``dmuon.stage_in``, also inside the
+        microbatch scan of the pre-staging path.  Unlike the fused path's
+        stage_in, it holds no momentum: that runs in ``compute``."""
         out = {}
-        for key in keys:
-            g = self.plan.groups[key]
-            leaves = {p: (grads[p] if dtype is None
-                          else grads[p].astype(dtype))
-                      for p in g.leaf_paths}
-            out[group_key_str(key)] = self.layout.pack(key, leaves)
+        with jax.named_scope("dmuon.stage_in"):
+            for key in keys:
+                g = self.plan.groups[key]
+                leaves = {p: (grads[p] if dtype is None
+                              else grads[p].astype(dtype))
+                          for p in g.leaf_paths}
+                out[group_key_str(key)] = self.layout.pack(key, leaves)
         return out
 
     def stage_in_all(self, grads: Dict[str, jax.Array], *,
@@ -198,7 +203,12 @@ class BucketPipeline:
     def compute(self, keys, staged: Dict[str, jax.Array], momentum, step,
                 vstate):
         """Momentum + the variant's orthogonalizer for one bucket, on the
-        owner-local slice only."""
+        owner-local slice only.
+
+        The orthogonalizer runs under the named scope
+        ``dmuon.orthogonalize``; the momentum update before it lies under
+        no ``dmuon.*`` scope in this bucketed path (in the fused path it is
+        part of ``dmuon.stage_in``)."""
         cfg = self.cfg
         pdt = jnp.dtype(cfg.pack_dtype)
         mdt = jnp.dtype(cfg.momentum_dtype)
@@ -210,23 +220,27 @@ class BucketPipeline:
             new_mom[skey] = self.layout.constrain(mom.astype(mdt))
             eff[skey] = self.layout.constrain(e)
         skeys = [group_key_str(k) for k in keys]
-        ortho, new_sub = self.ortho(eff, step=step,
-                                    state=_slice_state(vstate, skeys),
-                                    layout=self.layout, cfg=cfg)
+        with jax.named_scope("dmuon.orthogonalize"):
+            ortho, new_sub = self.ortho(eff, step=step,
+                                        state=_slice_state(vstate, skeys),
+                                        layout=self.layout, cfg=cfg)
         return ortho, new_mom, new_sub
 
     def publish(self, keys, ortho: Dict[str, jax.Array], params_matrix):
-        """Staged reshard back to the training layout + scale / wd / lr."""
+        """Staged reshard back to the training layout + scale / wd / lr,
+        under the named scope ``dmuon.publish``."""
         cfg = self.cfg
         pdt = jnp.dtype(cfg.pack_dtype)
         updates = {}
-        for key in keys:
-            skey = group_key_str(key)
-            m, n = self.plan.groups[key].key
-            s = scale_factor(m, n, cfg.scale_mode)
-            per_leaf = self.layout.unpack(key, ortho[skey].astype(pdt) * s)
-            for p, upd in per_leaf.items():
-                updates[p] = apply_wd_and_lr(upd, params_matrix[p], cfg)
+        with jax.named_scope("dmuon.publish"):
+            for key in keys:
+                skey = group_key_str(key)
+                m, n = self.plan.groups[key].key
+                s = scale_factor(m, n, cfg.scale_mode)
+                per_leaf = self.layout.unpack(key,
+                                              ortho[skey].astype(pdt) * s)
+                for p, upd in per_leaf.items():
+                    updates[p] = apply_wd_and_lr(upd, params_matrix[p], cfg)
         return updates
 
     # ---------------------------------------------------------- schedules

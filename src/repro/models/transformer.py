@@ -189,19 +189,26 @@ def _pin_batch(cfg: ArchConfig, x: jax.Array) -> jax.Array:
 def _block_apply(cfg: ArchConfig, p: Params, x: jax.Array, *,
                  rope_cs, window_enabled=None, cache=None, ssm_state=None,
                  pos=None, block_table=None):
-    """Residual block. Returns (x, new_cache, new_ssm_state)."""
+    """Residual block. Returns (x, new_cache, new_ssm_state).
+
+    Attention and the dense MLP run under the named scopes
+    ``model.attention`` and ``model.mlp``: each is one ``/`` segment of the
+    ``op_name`` of every HLO instruction they produce, in the forward, the
+    backward and the remat recompute alike (a profiler trace's per-layer
+    split reads them)."""
     x = _pin_batch(cfg, x)
     h = layers.rmsnorm(p["attn_norm"], x, cfg.norm_eps)
     new_cache = new_ssm = None
-    if cfg.attn_kind == "mla":
-        attn_out, new_cache = layers.mla_attention(
-            p["attn"], cfg.mla, h, cache=cache, pos=pos, rope_cs=rope_cs,
-            block_table=block_table)
-    else:
-        attn_out, new_cache = layers.attention(
-            p["attn"], cfg.attn_cfg(), h, cache=cache, pos=pos,
-            rope_cs=rope_cs, window_enabled=window_enabled,
-            block_table=block_table)
+    with jax.named_scope("model.attention"):
+        if cfg.attn_kind == "mla":
+            attn_out, new_cache = layers.mla_attention(
+                p["attn"], cfg.mla, h, cache=cache, pos=pos, rope_cs=rope_cs,
+                block_table=block_table)
+        else:
+            attn_out, new_cache = layers.attention(
+                p["attn"], cfg.attn_cfg(), h, cache=cache, pos=pos,
+                rope_cs=rope_cs, window_enabled=window_enabled,
+                block_table=block_table)
     if cfg.family == "hybrid":
         ssm_out, new_ssm = ssm_lib.ssm(p["ssm"], cfg.ssm, h, state=ssm_state)
         s = p["mix_scale"].astype(jnp.float32)
@@ -212,7 +219,9 @@ def _block_apply(cfg: ArchConfig, p: Params, x: jax.Array, *,
     if cfg.moe is not None:
         x = x + moe_lib.moe(p["moe"], cfg.moe, h)
     else:
-        x = x + layers.mlp(p["mlp"], h, cfg.act)
+        with jax.named_scope("model.mlp"):
+            mlp_out = layers.mlp(p["mlp"], h, cfg.act)
+        x = x + mlp_out
     return x, new_cache, new_ssm
 
 

@@ -354,42 +354,58 @@ class ResilientLoop:
             if ev.kind == "preempt":
                 raise Preemption()
             if ev.kind == "readd":
-                self._resize_owners(self.num_owners + 1, kind="readd",
-                                    step=step)
+                import jax
+                with jax.profiler.TraceAnnotation("loop.recover"):
+                    self._resize_owners(self.num_owners + 1, kind="readd",
+                                        step=step)
 
     def run(self) -> LoopReport:
+        """Run to ``rcfg.steps``.  Each iteration is a profiler step span
+        (``train``) holding ``loop.data`` (the next batch), ``loop.step``
+        (the step call through ``block_until_ready``), ``loop.report`` (the
+        loss read, the monitor, the log line and the rebalance check) and
+        ``loop.checkpoint``; owner loss, re-add and preemption run in
+        ``loop.recover``.  The spans cost about a microsecond each when no
+        profiler runs."""
         import jax
+        from jax.profiler import StepTraceAnnotation, TraceAnnotation
         step = int(np.asarray(self.state.step))
         try:
             while step < self.rcfg.steps:
                 try:
                     self._raise_faults(step)
                 except OwnerLost as e:
-                    self._resize_owners(self.num_owners - 1, kind="kill",
-                                        step=step, owner=e.owner)
+                    with TraceAnnotation("loop.recover"):
+                        self._resize_owners(self.num_owners - 1, kind="kill",
+                                            step=step, owner=e.owner)
                     continue                 # re-poll the same step
                 except Preemption:
-                    step = self._recover_preemption(step)
+                    with TraceAnnotation("loop.recover"):
+                        step = self._recover_preemption(step)
                     continue
 
-                batch = next(self.pipe)
-                with self.timer:
-                    self.state = self.step_fn(self.state, batch)
-                    jax.block_until_ready(self.state.loss_ema)
-                self.report.executed_steps += 1
-                self.report.losses[step] = float(self.state.loss_ema)
-                self.report.step_times.append(self.timer.last)
-                self.monitor.record(self._owner_times(self.timer.last))
-                step += 1
-                if step % 10 == 0:
-                    self.log(f"step {step:5d} loss_ema "
-                             f"{float(self.state.loss_ema):.4f} "
-                             f"{np.mean(self.timer.recent(10))*1e3:.0f} "
-                             f"ms/step")
-
-                self._maybe_rebalance(step)
-                if self.rcfg.ckpt_every and step % self.rcfg.ckpt_every == 0:
-                    self._save_checkpoint(step)
+                with StepTraceAnnotation("train", step_num=step):
+                    with TraceAnnotation("loop.data"):
+                        batch = next(self.pipe)
+                    with TraceAnnotation("loop.step"), self.timer:
+                        self.state = self.step_fn(self.state, batch)
+                        jax.block_until_ready(self.state.loss_ema)
+                    self.report.executed_steps += 1
+                    with TraceAnnotation("loop.report"):
+                        self.report.losses[step] = float(self.state.loss_ema)
+                        self.report.step_times.append(self.timer.last)
+                        self.monitor.record(self._owner_times(self.timer.last))
+                        step += 1
+                        if step % 10 == 0:
+                            ms = np.mean(self.timer.recent(10)) * 1e3
+                            self.log(f"step {step:5d} loss_ema "
+                                     f"{float(self.state.loss_ema):.4f} "
+                                     f"{ms:.0f} ms/step")
+                        self._maybe_rebalance(step)
+                    if (self.rcfg.ckpt_every
+                            and step % self.rcfg.ckpt_every == 0):
+                        with TraceAnnotation("loop.checkpoint"):
+                            self._save_checkpoint(step)
         finally:
             self.pipe.close()
             if self.mgr is not None:
