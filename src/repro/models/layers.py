@@ -16,11 +16,14 @@ Conventions:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from repro.kernels import flash
 
 Params = Dict[str, Any]
 
@@ -187,6 +190,15 @@ def _paged_read(leaf, block_table):
     B, W = block_table.shape
     g = leaf[block_table]                       # (B, W, bs, ...)
     return g.reshape((B, W * leaf.shape[1]) + leaf.shape[2:])
+
+
+def _one_device(x: jax.Array) -> bool:
+    """Whether x's program runs on one device: no mesh of more than one
+    device on its type (committed mesh-sharded inputs put theirs there) nor
+    set around the trace.  A Pallas call on a larger mesh would need a
+    ``shard_map``."""
+    return (jax.typeof(x).sharding.mesh.size <= 1
+            and jax.sharding.get_abstract_mesh().size <= 1)
 
 
 def _sdpa(q, k, v, *, scale, qpos=None, kpos=None, causal=False,
@@ -366,18 +378,33 @@ def attention(p: Params, cfg: AttnConfig, x: jax.Array, *,
         k = jax.lax.with_sharding_constraint(k, kv_pin)
         v = jax.lax.with_sharding_constraint(v, kv_pin)
     seq_pinned = cfg.seq_axis is not None and S > 1
+    scale = 1.0 / math.sqrt(hd)
     if not cfg.causal or xk is not None:
-        out = _sdpa(q, k, v, scale=1.0 / math.sqrt(hd),
-                    q_one_block=seq_pinned)
+        out = _sdpa(q, k, v, scale=scale, q_one_block=seq_pinned)
     else:
         offset = pos if pos is not None else 0
         qpos = offset[:, None] + jnp.arange(S) if jnp.ndim(offset) == 1 \
             else offset + jnp.arange(S)
-        out = _sdpa(q, k, v, scale=1.0 / math.sqrt(hd),
-                    qpos=qpos, kpos=jnp.arange(T), causal=True,
-                    window=cfg.sliding_window,
-                    window_enabled=window_enabled,
-                    q_one_block=seq_pinned)
+
+        def sdpa(q, k, v):
+            return _sdpa(q, k, v, scale=scale,
+                         qpos=qpos, kpos=jnp.arange(T), causal=True,
+                         window=cfg.sliding_window,
+                         window_enabled=window_enabled,
+                         q_one_block=seq_pinned)
+
+        if (cache is None and pos is None and cfg.sliding_window is None
+                and not seq_pinned and S == T and S > _Q_CHUNK
+                and S % flash.BLOCK == 0 and _one_device(q)):
+            # long causal self-attention in training: the flash-attention
+            # kernel wherever this is lowered for a TPU (a compile for a
+            # described one too); other platforms keep the chunked softmax
+            out = jax.lax.platform_dependent(
+                q, k, v, default=sdpa,
+                tpu=functools.partial(flash.causal_attention, scale=scale,
+                                      interpret=False))
+        else:
+            out = sdpa(q, k, v)
     if cfg.seq_axis is not None and S > 1:
         out = jax.lax.with_sharding_constraint(
             out, _P(cfg.batch_axes, cfg.seq_axis, None, None))

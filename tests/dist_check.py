@@ -12,6 +12,8 @@ Checks, on a (2, 4) ('data','model') mesh:
      style collectives rather than a full all-gather of every gradient plus
      replicated NS (structural check of the communication pattern);
   4. sharded AdamW path still works for non-matrix leaves.
+Later cases (numbered in ``main``) cover the bucketed pipelines, the Pallas
+kernels inside the owner-local shard_map and attention's kernel dispatch.
 """
 
 import os
@@ -27,6 +29,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import api
 from repro.core.gram_ns import GramNSConfig
 from repro.core.muon import MuonConfig
+from repro.models import layers
 from repro.runtime.elastic import make_mesh
 
 
@@ -206,6 +209,23 @@ def main():
             rtol=1e-4, atol=1e-4,
             err_msg="/".join(str(getattr(k, 'key', k)) for k in kp))
     print("Pallas kernels inside shard_map under mesh: OK")
+
+    # (9) long causal self-attention keeps the chunked online softmax when
+    # its inputs are split over the mesh: a Pallas call there would need a
+    # shard_map.  One device's trace holds the kernel (in its TPU branch).
+    acfg = layers.AttnConfig(d_model=64, n_heads=6, n_kv_heads=2,
+                             head_dim=64)
+    ap = layers.attention_init(jax.random.PRNGKey(2), acfg)
+    x = jax.random.normal(jax.random.PRNGKey(3), (8, 2048, 64))
+
+    def attn_grad(p, x):
+        return jax.grad(lambda p, x: jnp.sum(
+            layers.attention(p, acfg, x)[0]))(p, x)
+    ap_sh = jax.device_put(ap, NamedSharding(mesh, P()))
+    x_sh = jax.device_put(x, NamedSharding(mesh, P("data")))
+    assert "pallas_call" not in str(jax.make_jaxpr(attn_grad)(ap_sh, x_sh))
+    assert "pallas_call" in str(jax.make_jaxpr(attn_grad)(ap, x))
+    print("attention under mesh keeps the chunked path: OK")
     print("ALL DISTRIBUTED CHECKS PASSED")
 
 

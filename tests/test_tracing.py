@@ -50,6 +50,40 @@ def _compiled_op_names(muon: MuonConfig, accum_steps: int = 1):
     return OP_NAME.findall(text)
 
 
+_FUNC = re.compile(r"^\s*func\.func (?:public|private) @([\w.\-]+)")
+_CALL = re.compile(r"\bcall @([\w.\-]+)\(")
+_LOC_REF = re.compile(r"loc\((#loc\d+)\)\s*$")
+_LOC_DEF = re.compile(r'^(#loc\d+) = loc\("([^"]*)"', re.M)
+
+
+def _mosaic_op_names(text):
+    """Op name of each Mosaic call in StableHLO text lowered for a TPU: its
+    location's name, after those of the calls that reach its function (XLA
+    joins them into the compiled instruction's ``op_name``)."""
+    names = dict(_LOC_DEF.findall(text))
+    calls, kernels, fn = {}, {}, None
+    for line in text.splitlines():
+        m = _FUNC.match(line)
+        if m:
+            fn = m.group(1)
+            continue
+        ref = _LOC_REF.search(line)
+        name = names.get(ref.group(1), "") if ref else ""
+        if "tpu_custom_call" in line:
+            kernels.setdefault(fn, []).append(name)
+        elif _CALL.search(line):
+            calls.setdefault(_CALL.search(line).group(1), []).append(
+                (fn, name))
+
+    def prefixes(fn):
+        if fn == "main":
+            return [""]
+        return [p + name + "/" for caller, name in calls.get(fn, [])
+                for p in prefixes(caller)]
+    return [p + n for fn, ns in kernels.items() for n in ns
+            for p in prefixes(fn)]
+
+
 def _with_scope(names, scope):
     return [n for n in names if scope in n.split("/")]
 
@@ -111,6 +145,28 @@ def test_prestaged_accumulation_stages_in_under_its_scope():
     found = _with_scope(names, "dmuon.stage_in")
     assert found and not any(_in_gradient(n) for n in found)
     assert _with_scope(names, "dmuon.orthogonalize")
+
+
+def test_attention_kernel_sits_in_the_gradient_under_its_scope():
+    """At S = 2048 the step lowered for a TPU runs attention in Mosaic
+    kernels (the forward, its remat recompute, dq, dk/dv): each carries
+    ``model.attention`` under ``jvp(``/``transpose(``, so ``fwd_bwd_ms``
+    counts it and ``optimizer_ms`` does not."""
+    cfg = _cfg()
+    key = jax.random.PRNGKey(0)
+    shapes = jax.eval_shape(lambda k: model_fns(cfg).init(cfg, k), key)
+    plan = api.dedicate_params(shapes, num_owners=2, strategy="greedy")
+    opt = api.Muon(plan, None, config=MuonConfig(mode="owner"))
+    step = make_train_step(cfg, opt, None)
+    state = jax.eval_shape(lambda: init_state(cfg, opt, key))
+    tok = jax.ShapeDtypeStruct((2, 2048), jnp.int32)
+    text = step.trace(state, {"tokens": tok, "labels": tok}).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    names = _mosaic_op_names(text)
+    assert len(names) >= 3, names
+    for name in names:
+        assert "model.attention" in name.split("/"), name
+        assert _in_gradient(name), name
 
 
 def _host_spans(path):
