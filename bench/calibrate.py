@@ -3,10 +3,10 @@
     python3 bench/calibrate.py --workload <cell> --seeds 1 2 3 \\
         [--faults none fp8 half_batch frozen swap bf16 ns_high ...]
 
-For each fault in turn, each seed builds the cell's loop at its own size,
-runs its check steps and the reference, and prints one JSON line with every
-number of the comparison, compared by the cell or not, and whether the
-cell's limits pass it.  ``none`` is the program as it is (the lower readings
+For each seed in turn the reference runs once; then, for each fault, the
+cell's loop is built at its own size and runs its check steps, and one JSON
+line gives every number of their comparison with that reference, compared
+by the cell or not, and whether the cell's limits pass it.  ``none`` is the program as it is (the lower readings
 of PERF.md, section 2).  ``fp8`` is the control: the reference with float8
 products put in the program's place.  The others are the driver's
 ``FAULTS``, planted in the program.  No window runs, nothing is timed, and
@@ -24,23 +24,21 @@ import time
 import run as bench_run   # noqa: E402  (bench/run.py sets up sys.path)
 
 
-def control(cell: dict, seed: int) -> dict:
-    """The comparison's readings with the reference at float8 products in
-    the program's place, on the cell's own check steps."""
+def reference(cell: dict, seed: int, fp8: bool = False) -> dict:
+    """The reference's readings on the cell's own check steps from
+    ``seed``, its changes copied off the chip (as the program's are);
+    ``fp8``: at float8 products, the control."""
     import jax
 
     from bench.traffic import ZipfTokens
-    traffic, conf, ref = cell["traffic"], cell["config"], cell["reference"]
+    traffic, conf = cell["traffic"], cell["config"]
     gen = ZipfTokens(traffic, conf, seed)
     batches = [gen.batch_at(k) for k in range(int(traffic["check_steps"]))]
-    mode = traffic["optimizer"].get("mode", "owner")
-    devices = jax.devices()[:cell["chips"]]
-    low = ref.train_steps(conf, batches, seed, mode, fp8=True,
-                          devices=devices)
-    low["deltas"] = jax.device_get(low["deltas"])  # off the chip, as the
-    # program's are, while the reference runs
-    return cell["driver"].compare(low, ref.train_steps(
-        conf, batches, seed, mode, devices=devices))
+    out = cell["reference"].train_steps(
+        conf, batches, seed, traffic["optimizer"].get("mode", "owner"),
+        fp8=fp8, devices=jax.devices()[:cell["chips"]])
+    out["deltas"] = jax.device_get(out["deltas"])
+    return out
 
 
 def main() -> int:
@@ -61,14 +59,20 @@ def main() -> int:
     bench_run.enable_cache()
     limits = cell["limits"]
     cell["limits"] = {k: limits.get(k, math.inf) for k in drv.CHECKS}
-    for fault in args.faults:
-        for seed in args.seeds:
+    for seed in args.seeds:
+        t0 = time.perf_counter()
+        ref = reference(cell, seed)
+        print(json.dumps({"cell": args.workload, "seed": seed,
+                          "reference_s": round(time.perf_counter() - t0, 1)}),
+              flush=True)
+        for fault in args.faults:
             t0 = time.perf_counter()
             if fault == "fp8":
-                readings = control(cell, seed)
+                readings = drv.compare(reference(cell, seed, fp8=True), ref)
             else:
                 out = drv.run(cell, seed, 0.0, False, t0, window=False,
-                              fault=None if fault == "none" else fault)
+                              fault=None if fault == "none" else fault,
+                              ref=ref)
                 readings = {k: v["value"] for k, v in out["checks"].items()}
             correct = all(readings[k] <= v for k, v in limits.items())
             print(json.dumps({"cell": args.workload, "fault": fault,

@@ -18,6 +18,7 @@ the plain reference runs the same check steps from the same seed.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import gc
 import math
 import shutil
@@ -37,7 +38,7 @@ CHECKS = ("loss_gap", "grad_norm_gap", "update_norm_gap", "update_dir_gap")
 # Faults planted in the program for the checks' own tests and calibration
 # (see ``plant``).
 FAULTS = ("bf16", "half_batch", "frozen", "swap", "ns_high", "ns_default",
-          "ns_bf16")
+          "ns_bf16", "shard_ns")
 # Leaves whose reference gradient is below this share of the median leaf's
 # (a key bias under softmax) move under AdamW by round-off alone; they are
 # left out of the change comparison.
@@ -247,8 +248,12 @@ def plant(loop, fault: str | None):
     trade places, a misrouting that keeps every norm.  ``ns_high`` and
     ``ns_default``: every Gram Newton-Schulz product at ``Precision.HIGH``
     (three bfloat16 passes) or the default (one), below the configuration's
-    HIGHEST.  ``bf16``, ``ns_bf16`` and ``half_batch`` are set in ``run``
-    through the program's own options and the feed."""
+    HIGHEST.  ``shard_ns`` (a mesh only): each chip's block of each Muon
+    matrix, as the training layout splits it over the mesh, is
+    orthogonalized on its own, in place of the whole matrix at its owner:
+    the update of a Muon that leaves out the exchange between chips.
+    ``bf16``, ``ns_bf16`` and ``half_batch`` are set in ``run`` through the
+    program's own options and the feed."""
     import jax
     import jax.numpy as jnp
     opt = loop.opt
@@ -285,7 +290,61 @@ def plant(loop, fault: str | None):
         def restore():
             ref.bmm, gram_ns.bmm = kept
         return restore
+    elif fault == "shard_ns":
+        from repro.core import muon
+        from repro.core.owner_comms import group_key_str
+        if loop.mesh is None:
+            raise ValueError("shard_ns needs a mesh")
+        # which axis of each owner stack's (m, n) matrices the training
+        # layout splits, and over how many chips
+        specs = {k: v.sharding.spec
+                 for k, v in flat(loop.state.params).items()}
+        split = {}
+        for path in opt.plan.groups:
+            info = opt.plan.leaves[path]
+            spec = tuple(specs[path]) + (None,) * (len(info.shape)
+                                                   - len(specs[path]))
+            dims = spec[-2:][::-1] if info.transpose else spec[-2:]
+            for axis, names in ((1, dims[0]), (2, dims[1])):
+                if names is not None:
+                    names = names if isinstance(names, tuple) else (names,)
+                    split[group_key_str(path)] = (axis, math.prod(
+                        loop.mesh.shape[a] for a in names))
+                    break
+        if not split:
+            raise ValueError("shard_ns: no Muon matrix is split over the mesh")
+        kept = muon.make_orthogonalizer
+        muon.make_orthogonalizer = lambda name, cfg: functools.partial(
+            _shard_ns, split=split)
+
+        def restore():
+            muon.make_orthogonalizer = kept
+        return restore
     return lambda: None
+
+
+def _shard_ns(stacks, *, step, state, layout, cfg, split):
+    """The ``shard_ns`` fault's orthogonalizer (see ``plant``): Gram
+    Newton-Schulz of each block of each owner stack that the training layout
+    gives one chip; ``split`` maps a stack to ``(axis, chips)``."""
+    from repro.core.gram_ns import gram_newton_schulz
+
+    def blocks(x, how):
+        if how is None:
+            return gram_newton_schulz(x, cfg.ns)
+        axis, k = how
+        c, m, n = x.shape
+        if axis == 1:
+            o = gram_newton_schulz(x.reshape(c * k, m // k, n), cfg.ns)
+            return o.reshape(c, m, n)
+        b = x.reshape(c, m, k, n // k).transpose(0, 2, 1, 3)
+        o = gram_newton_schulz(b.reshape(c * k, m, n // k), cfg.ns)
+        return o.reshape(c, k, m, n // k).transpose(0, 2, 1, 3).reshape(
+            c, m, n)
+
+    return layout.shard_local(
+        lambda sts: {s: blocks(x, split.get(s)) for s, x in sts.items()},
+        stacks), state
 
 
 class CompileCounter:
@@ -302,7 +361,8 @@ class CompileCounter:
 
 
 def run(cell: dict, seed: int, seconds: float, trace: bool, t0: float, *,
-        fault: str | None = None, window: bool = True) -> dict:
+        fault: str | None = None, window: bool = True,
+        ref: dict | None = None) -> dict:
     """One run of a training cell; returns the result line as a dict.
 
     ``fault`` (one of ``FAULTS``) changes the program for the checks' own
@@ -310,7 +370,8 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, t0: float, *,
     ``"ns_bf16"`` its Gram Newton-Schulz in bfloat16, ``"half_batch"``
     feeds the step the first half of each batch, and the others are
     planted by ``plant``.  ``window=False`` stops after the check steps and
-    the comparison."""
+    the comparison.  ``ref``: the reference's readings of this seed, made
+    beforehand (calibration compares one seed under several faults)."""
     import jax
     import jax.numpy as jnp
 
@@ -356,7 +417,16 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, t0: float, *,
     finally:
         unplant()
     log(f"[setup] {time.perf_counter() - t0:.1f} s: step lowered")
-    compiled = loop.step_fn = lowered.compile()
+    # A traced run reads each instruction's op_name from this executable:
+    # key its compile on the metadata too, so that the names are this
+    # checkout's own and not those that a checkout of equal operations left
+    # in a shared cache.  Every other program keeps the untraced key.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", trace)
+    try:
+        compiled = loop.step_fn = lowered.compile()
+    finally:
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          False)
     mem = compiled.memory_analysis()
     live = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     log(f"[setup] {time.perf_counter() - t0:.1f} s: compiled step, "
@@ -452,8 +522,10 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, t0: float, *,
     del p3
     prog["delta_norms"] = {k: float(np.sqrt(np.sum(np.square(
         v, dtype=np.float64)))) for k, v in prog["deltas"].items()}
-    ref = ref_mod.train_steps(conf, [gen.batch_at(k) for k in range(n_check)],
-                              seed, opt.get("mode", "owner"), devices=devices)
+    if ref is None:
+        ref = ref_mod.train_steps(
+            conf, [gen.batch_at(k) for k in range(n_check)], seed,
+            opt.get("mode", "owner"), devices=devices)
     readings = compare(prog, ref)
     log(f"[reference] {time.perf_counter() - tr0:.1f} s; program losses "
         f"{prog['losses']} reference {ref['losses']}")
@@ -475,7 +547,8 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, t0: float, *,
 def _reduce_trace(tdir: str, hlo: str, ctx: dict) -> dict:
     """Device time per class (``fwd_bwd``: ops of the step program whose
     op_name lies under ``jvp(`` or ``transpose(``; ``optimizer``: every
-    other op of the step program), into ``ctx["trace"]``."""
+    other op of the step program), into ``ctx["trace"]``, and the step's
+    ``{instruction: op_name}`` map, into ``ctx["op_names"]``."""
     import glob
     path = sorted(glob.glob(f"{tdir}/**/*.xplane.pb", recursive=True))[-1]
     devices, hosts = tr.load(path)
@@ -488,9 +561,11 @@ def _reduce_trace(tdir: str, hlo: str, ctx: dict) -> dict:
         return "fwd_bwd" if ("jvp(" in op or "transpose(" in op) \
             else "optimizer"
 
+    kinds = tr.collective_kinds(hlo)
     used = sorted(devices)[:ctx["chips"]]
-    red = {d: tr.reduce_device(devices[d], classify) for d in used}
+    red = {d: tr.reduce_device(devices[d], classify, kinds) for d in used}
     ctx["trace"] = red
+    ctx["op_names"] = names
     busy = statistics.mean(r["busy_ns"] for r in red.values()) * 1e-9
     ops = {}
     for r in red.values():
@@ -509,3 +584,4 @@ def _reduce_trace(tdir: str, hlo: str, ctx: dict) -> dict:
         "device_ops": [[f"{k} {names.get(k, '')[-80:]}".strip(), v]
                        for k, v in top],
         "idle_gaps": tr.label_gaps(first["gaps"], hosts, span)}}
+

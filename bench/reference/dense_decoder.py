@@ -30,6 +30,7 @@ the rest.
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -254,18 +255,15 @@ def _train_steps(cfg, batches, seed, mode, fp8, devices):
     grad_row = jax.jit(jax.value_and_grad(
         lambda p, t, l: _loss_sum(cfg, p, t, l, fp8)))
 
-    @jax.jit
-    def update(params, grads, state, t):
-        new_p, new_s = {}, {}
-        for k, g in grads.items():
-            if k in muon:
-                u, mom = _muon_update(g, state[k][0])
-                new_s[k] = (mom,)
-            else:
-                u, mu, nu = _adamw_update(g, *state[k], t)
-                new_s[k] = (mu, nu)
-            new_p[k] = params[k] + u
-        return new_p, new_s
+    # One leaf at a time, its parameter and state donated: a four-chip
+    # stage's whole update does not fit beside its Newton-Schulz temporaries.
+    @functools.partial(jax.jit, static_argnums=0, donate_argnums=(1, 3))
+    def update(is_muon, p, g, s, t):
+        if is_muon:
+            u, mom = _muon_update(g, s[0])
+            return p + u, (mom,)
+        u, mu, nu = _adamw_update(g, *s, t)
+        return p + u, (mu, nu)
 
     norms = jax.jit(lambda tree: {k: _norm(v) for k, v in tree.items()})
     losses, grad_norms = [], None
@@ -282,8 +280,9 @@ def _train_steps(cfg, batches, seed, mode, fp8, devices):
         losses.append(total / n)
         if grad_norms is None:
             grad_norms = {k: float(v) for k, v in norms(grads).items()}
-        params, state = update(params, grads, state, float(step + 1))
-        del grads
+        for k in list(grads):
+            params[k], state[k] = update(k in muon, params[k], grads.pop(k),
+                                         state[k], float(step + 1))
     del state
     delta = jax.tree.map(jnp.subtract, params, p0)
     del params, p0

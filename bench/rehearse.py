@@ -9,8 +9,11 @@ built as ``ResilientLoop`` builds it (``make_train_step`` on the dedication
 plan, the state donated), lowered at the cell's batch with every argument
 placed on a described ``v5e:2x2`` (one chip, or a (1, 4) mesh over all four
 as ``remesh()`` lays it), and compiled by the TPU compiler.  It prints the
-compiled ``memory_analysis()`` per device and the count of each collective
-in the compiled HLO.  A compile that passes is not a chip run.
+compiled ``memory_analysis()`` per device and the count of each kind of
+collective instruction in the compiled HLO, as the trace reduction classifies
+them (``bench.trace.collective_kinds``: an asynchronous collective's start
+and done halves, and a collective fusion's body, each count).  A compile
+that passes is not a chip run.
 """
 
 from __future__ import annotations
@@ -18,19 +21,15 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import re
 import sys
 import time
+from collections import Counter
 from pathlib import Path
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 ROOT = Path(__file__).resolve().parents[1]
 sys.path[0] = str(ROOT)
 sys.path.insert(1, str(ROOT / "src"))
-
-COLLECTIVES = ("all-to-all", "all-gather", "all-reduce", "reduce-scatter",
-               "collective-permute")
-
 
 def rehearse(config: dict, traffic: dict, chips: int) -> dict:
     import jax
@@ -39,6 +38,7 @@ def rehearse(config: dict, traffic: dict, chips: int) -> dict:
     from jax.sharding import NamedSharding, PartitionSpec as P
     from jax.sharding import SingleDeviceSharding
 
+    from bench import trace
     from bench.drivers.train import program_config
     from repro.core import api
     from repro.core.muon import MuonConfig
@@ -85,8 +85,8 @@ def rehearse(config: dict, traffic: dict, chips: int) -> dict:
         "temp_gib": mem.temp_size_in_bytes / gib,
         "live_gib": (mem.argument_size_in_bytes
                      + mem.temp_size_in_bytes) / gib,
-        "collectives": {c: len(re.findall(rf"\s{c}(?:-start)?\(", text))
-                        for c in COLLECTIVES},
+        "collectives": dict(sorted(Counter(
+            trace.collective_kinds(text).values()).items())),
         "tpu_custom_calls": text.count("tpu_custom_call"),
     }
 

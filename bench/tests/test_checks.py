@@ -77,27 +77,59 @@ def test_float8_reference_fails_as_the_control():
 
 FOUR = """
 import sys, time
-from bench.tests.test_checks import tiny_cell
-for fault, want in ((None, True), ("half_batch", False)):
+import jax
+from bench.tests.test_checks import TINY_LIMITS, tiny_cell
+from bench.traffic import ZipfTokens
+for fault, want in ((None, True), ("half_batch", False), ("frozen", False),
+                    ("swap", False), ("shard_ns", False)):
     cell = tiny_cell()
     cell["chips"] = 4
     out = cell["driver"].run(cell, 2**31 + 7, 0.2, False, time.perf_counter(),
                              fault=fault)
     assert out["correct"] is want, (fault, out["checks"])
     assert out["device"]["count"] == 4
+    print(fault, {k: v["value"] for k, v in out["checks"].items()})
+ref, drv = cell["reference"], cell["driver"]
+gen = ZipfTokens(cell["traffic"], cell["config"], 7)
+batches = [gen.batch_at(k) for k in range(3)]
+readings = drv.compare(
+    ref.train_steps(cell["config"], batches, 7, "owner", fp8=True,
+                    devices=jax.devices()),
+    ref.train_steps(cell["config"], batches, 7, "owner",
+                    devices=jax.devices()))
+assert any(v > TINY_LIMITS[k] for k, v in readings.items()), readings
 print("four devices ok")
 """
 
 
 def test_a_four_device_cell_runs_on_a_mesh_and_a_split_reference():
     """A cell on four devices: the program on ``remesh()``'s (1, 4) mesh
-    with four owners, the reference split over the same four devices."""
+    with four owners, the reference split over the same four devices.  The
+    program as it is passes; each fault a four-chip training cell can have
+    makes ``correct`` false (a state left unchanged, half of the batch left
+    out, the exchange between chips left out: ``shard_ns``), as do a
+    misrouted update and the float8 control on the split reference."""
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                PYTHONPATH=os.pathsep.join([str(ROOT), str(ROOT / "src")]))
     r = subprocess.run([sys.executable, "-c", FOUR], cwd=ROOT, env=env,
                        capture_output=True, text=True, timeout=600)
     assert r.returncode == 0 and "four devices ok" in r.stdout, r.stderr[-3000:]
+
+
+@pytest.mark.parametrize("name", [c["name"] for c in json.loads(
+    (ROOT / "BENCHMARK.json").read_text())["configs"]])
+def test_each_configuration_is_the_programs(name):
+    """Each configuration file gives the program's own sizes, and lists
+    every key it changes from its source, with the published value."""
+    from bench.drivers.train import program_config
+    spec = {c["name"]: c for c in json.loads(
+        (ROOT / "BENCHMARK.json").read_text())["configs"]}[name]
+    conf = json.loads((ROOT / spec["file"]).read_text())
+    program_config(conf, None)
+    assert conf["source"] == spec["source"]
+    assert conf["reduced"] == spec["reduced"]
+    assert sorted(conf.get("published", {})) == sorted(spec["reduced"])
 
 
 def run_py(cwd):

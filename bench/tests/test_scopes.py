@@ -7,6 +7,7 @@ recorded on a TPU v5e from a program without scopes.  CPU only.
 
 from __future__ import annotations
 
+import shutil
 from pathlib import Path
 
 import pytest
@@ -116,10 +117,25 @@ def test_recorded_trace_of_a_program_without_scopes(readers):
     """A v5e trace of a step with no named scopes reads None, as a parent
     commit without them does."""
     devices, _ = tr.load(str(DATA / "tiny_v5e.xplane.pb"))
-    names = tr.op_names((DATA / "tiny_v5e_hlo.txt").read_text())
-    red = {d: tr.reduce_device(evs, lambda n, s: None)
+    hlo = (DATA / "tiny_v5e_hlo.txt").read_text()
+    names, kinds = tr.op_names(hlo), tr.collective_kinds(hlo)
+    red = {d: tr.reduce_device(evs, lambda n, s: None, kinds)
            for d, evs in devices.items()}
     ctx = {"steps": 3, "trace": red, "op_names": names}
     assert sum(red["/device:TPU:0"]["ops"].values()) > 0
     for name in READERS:
         assert readers[name].read(ctx) is None
+
+
+def test_the_driver_hands_the_readers_its_map(tmp_path):
+    """``_reduce_trace`` keeps the step's ``{instruction: op_name}`` map in
+    ``ctx``, where the scope readers find it."""
+    from bench.drivers import train
+    prof = tmp_path / "plugins" / "profile" / "1"
+    prof.mkdir(parents=True)
+    shutil.copy(DATA / "tiny_v5e.xplane.pb", prof / "t.xplane.pb")
+    hlo = (DATA / "tiny_v5e_hlo.txt").read_text()
+    ctx = {"chips": 1, "steps": 3}
+    train._reduce_trace(str(tmp_path), hlo, ctx)
+    assert ctx["op_names"] == tr.op_names(hlo) and ctx["op_names"]
+    assert scopes.scopes_ms(ctx, "jit(step)") > 0

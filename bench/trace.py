@@ -8,7 +8,9 @@ plane has one):
                   summed per class, by a caller's classifier;
 * ``exposed_ns``  the time collective ops (all-to-all, all-gather, ...)
                   ran with no other op beside them on that device, and
-                  ``exposed_by``, the same for each kind of collective;
+                  ``exposed_by``, the same for each kind of collective.
+                  Which ops are collectives, and of which kind, is read from
+                  the compiled step's HLO by opcode (``collective_kinds``);
 * ``ops``         self time per op name, and ``gaps``, the idle intervals
                   between busy ones.
 
@@ -24,13 +26,76 @@ import re
 import sys
 from collections import defaultdict
 
-COLLECTIVE = re.compile(
-    r"all-to-all|all-gather|all-reduce|reduce-scatter|collective-permute"
-    r"|alltoall|allgather|allreduce")
-
-
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*?"
                     r"metadata=\{[^}]*?op_name=\"([^\"]*)\"")
+_HEAD = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+_OP = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(?:\(.*?\)|\S+)\s+"
+                 r"([\w\-]+)\(")
+_CALLS = re.compile(r"calls=%?([\w.\-]+)")
+KINDS = ("all-to-all", "all-gather", "all-reduce", "reduce-scatter",
+         "collective-permute")
+_PRODUCTS = ("convolution", "dot")
+
+
+def collective_kinds(hlo_text: str) -> dict:
+    """``{instruction: kind}`` for the collectives of a compiled HLO module,
+    by opcode: an ``all-to-all``, ``all-gather``, ``all-reduce``,
+    ``reduce-scatter`` or ``collective-permute`` instruction (or its
+    ``-start``/``-done`` half), and a fusion whose computation holds one and
+    computes no product (the TPU compiler's asynchronous collective start
+    and done fusions).  A fusion that computes a product beside its
+    collective (the compiler's collective matmul) is compute: the collective
+    runs hidden inside it."""
+    comps, cur = {}, None
+    for line in hlo_text.splitlines():
+        if not line.startswith((" ", "\t")):
+            m = _HEAD.match(line)
+            if m:
+                cur = comps.setdefault(m.group(1), [])
+            continue
+        m = _OP.match(line)
+        if m and cur is not None:
+            calls = _CALLS.search(line)
+            cur.append((m.group(1), m.group(2),
+                        calls.group(1) if calls else None))
+    seen = {}
+
+    def inside(comp):
+        """(collective kinds, whether a product is computed) in ``comp``
+        and the computations its fusions call."""
+        if comp not in seen:
+            seen[comp] = (set(), False)
+            kinds, product = set(), False
+            for _, op, calls in comps.get(comp, ()):
+                kind = _opcode_kind(op)
+                if kind:
+                    kinds.add(kind)
+                product |= op in _PRODUCTS
+                if op == "fusion" and calls:
+                    k, p = inside(calls)
+                    kinds |= k
+                    product |= p
+            seen[comp] = (kinds, product)
+        return seen[comp]
+
+    out = {}
+    for ops in comps.values():
+        for name, op, calls in ops:
+            kind = _opcode_kind(op)
+            if kind is None and op == "fusion" and calls:
+                kinds, product = inside(calls)
+                if kinds and not product:
+                    kind = sorted(kinds)[0]
+            if kind:
+                out[name] = kind
+    return out
+
+
+def _opcode_kind(op: str) -> str | None:
+    for suffix in ("-start", "-done"):
+        if op.endswith(suffix):
+            op = op[:-len(suffix)]
+    return op if op in KINDS else None
 
 
 def op_names(hlo_text: str) -> dict:
@@ -115,10 +180,9 @@ def _minus(a, b):
     return total
 
 
-def self_times(events):
-    """``[(event, self ns)]``: each event's duration less the time of the
-    events nested inside it on the same line.  Events that overlap without
-    one containing the other (asynchronous ops) are siblings."""
+def _nesting(events):
+    """``(order, child)``: the events sorted by start (longest first), and
+    the time of the events nested directly inside each on the same line."""
     order = sorted(events, key=lambda ev: (ev[1], -(ev[2] - ev[1])))
     child = [0.0] * len(order)
     stack = []
@@ -128,39 +192,41 @@ def self_times(events):
         if stack and order[stack[-1]][2] >= e:
             child[stack[-1]] += e - s
         stack.append(i)
+    return order, child
+
+
+def self_times(events):
+    """``[(event, self ns)]``: each event's duration less the time of the
+    events nested inside it on the same line.  Events that overlap without
+    one containing the other (asynchronous ops) are siblings."""
+    order, child = _nesting(events)
     return [(ev, (ev[2] - ev[1]) - c) for ev, c in zip(order, child)]
 
 
-def is_collective(name: str) -> bool:
-    return COLLECTIVE.search(name) is not None
-
-
-def collective_kind(name: str) -> str | None:
-    """``all-to-all``, ``all-gather``, ... for a collective op's name."""
-    m = COLLECTIVE.search(name)
-    if m is None:
-        return None
-    kind = m.group(0)
-    return {"alltoall": "all-to-all", "allgather": "all-gather",
-            "allreduce": "all-reduce"}.get(kind, kind)
-
-
-def reduce_device(events, classify):
+def reduce_device(events, classify, kinds):
     """One device's op events reduced as the module docstring says.
     ``classify(name, stats) -> class`` or None to leave an event out of
     the classes (it still counts as busy).  ``exposed_ns`` is the time
-    collective ops (by name) ran with no other op beside them."""
+    collective ops ran with no other op beside them: ``kinds`` (the step's
+    ``collective_kinds``) names them and their kind.  An op that holds
+    others (a loop) hides no collective: only ops with no nested op count
+    as compute."""
     busy = union([(s, e) for _, s, e, _ in events])
     class_ns = defaultdict(float)
     ops = defaultdict(float)
     comm, compute = defaultdict(list), []
-    for (name, s, e, stats), own in self_times(events):
+    order, child = _nesting(events)
+    for (name, s, e, stats), nested in zip(order, child):
+        own = (e - s) - nested
         ops[name] += own
         cls = classify(name, stats)
         if cls is not None:
             class_ns[cls] += own
-        kind = collective_kind(name)
-        (compute if kind is None else comm[kind]).append((s, e))
+        kind = kinds.get(name)
+        if kind is not None:
+            comm[kind].append((s, e))
+        elif not nested:
+            compute.append((s, e))
     gaps = [(a[1], b[0]) for a, b in zip(busy, busy[1:])]
     compute = union(compute)
     return {"busy_ns": _length(busy), "class_ns": dict(class_ns),
